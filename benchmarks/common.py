@@ -1,7 +1,8 @@
 """Shared harness for the paper-figure benchmarks.
 
 All figure benchmarks run on the N-node cluster SIMULATOR (SimTransport on
-this container's single CPU device) and report two kinds of numbers:
+whatever single device JAX offers; they name no device and are not chip
+measurements — chip_smoke.py is the run on a TPU) and report:
 
   * protocol metrics (hardware-independent): round trips / op, wire bytes /
     op, one-sided success fraction — these are what Storm's design actually
@@ -10,8 +11,8 @@ this container's single CPU device) and report two kinds of numbers:
     constants (CX4-IB-class: ~1.8us one-sided RT, ~2.7us RPC RT, 100Gbps
     links, per-message CPU costs for send/recv systems) — the absolute
     scale of Figs 4-6;
-  * CPU wall time is printed for transparency but is NOT the comparison
-    metric (one CPU core simulates the whole cluster).
+  * host wall time, printed for transparency but NOT the comparison metric
+    (it is not a device time on any chip).
 """
 from __future__ import annotations
 
@@ -20,12 +21,14 @@ import sys
 import time
 import pathlib
 
-sys.path.insert(0, str(pathlib.Path(__file__).resolve().parents[1] / "src"))
+ROOT = pathlib.Path(__file__).resolve().parents[1]
+sys.path.insert(0, str(ROOT / "src"))
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import chip
 from repro.core import rpc as R
 from repro.core import slots as sl
 from repro.core.datastructs import hashtable as ht
@@ -119,7 +122,9 @@ def make_tx_workload(t, cfg, layout, state, *, lanes, n_keys, seed):
 
 
 def time_jit(fn, *args, iters=3):
-    """Compile + time a jitted callable; returns (result, best_seconds)."""
+    """Compile + time a jitted callable; returns (result, best_seconds).
+    Compiles go through the persistent cache (repro.chip)."""
+    chip.use_compile_cache(ROOT)
     out = fn(*args)
     jax.block_until_ready(out)
     best = float("inf")

@@ -23,6 +23,7 @@ import numpy as np
 
 from common import (ModelFabric, csv_line, modeled_throughput_per_node,
                     populate, time_jit)
+from repro import tatp
 from repro.core import slots as sl
 from repro.core import telemetry as T
 from repro.core import txloop as txl
@@ -32,7 +33,6 @@ from repro.core.transport import SimTransport
 LANES = 16
 SUBSCRIBERS_PER_NODE = 160
 FAB = ModelFabric()
-RD, WR = 2, 1   # static read/write set sizes (masked per mix)
 MAX_ROUNDS = 4  # bounded retry (tx_loop); 1 reproduces single-shot
 
 
@@ -52,21 +52,13 @@ def run_config(name, n_nodes, *, use_onesided: bool, oversub: bool,
 
     def draw_tx():
         """Returns read_keys (N,L,RD,2), write_keys (N,L,WR,2), masks."""
-        def pick(n):
-            s = rng.randint(0, n_nodes, (n_nodes, lanes, n))
-            i = rng.randint(0, SUBSCRIBERS_PER_NODE, (n_nodes, lanes, n))
-            return (np.asarray(klo)[s, i], np.asarray(khi)[s, i])
-        rl, rh = pick(RD)
-        wl, wh = pick(WR)
-        kind = rng.rand(n_nodes, lanes)
-        is_read = kind < 0.80                 # read-only tx
-        two_reads = kind < 0.40               # GET_NEW_DESTINATION-like
-        read_en = np.ones((n_nodes, lanes, RD), bool)
-        read_en[..., 1] = two_reads
-        read_en[~is_read, 1] = False          # updates read 1 row
-        write_en = np.repeat((~is_read)[..., None], WR, axis=-1)
-        rk = jnp.asarray(np.stack([rl, rh], -1), jnp.uint32)
-        wk = jnp.asarray(np.stack([wl, wh], -1), jnp.uint32)
+        (rs, ri), (ws, wi), read_en, write_en = tatp.draw_mix(
+            rng, n_nodes, SUBSCRIBERS_PER_NODE, lanes)
+        klo_np, khi_np = np.asarray(klo), np.asarray(khi)
+        rk = jnp.asarray(np.stack([klo_np[rs, ri], khi_np[rs, ri]], -1),
+                         jnp.uint32)
+        wk = jnp.asarray(np.stack([klo_np[ws, wi], khi_np[ws, wi]], -1),
+                         jnp.uint32)
         return rk, wk, jnp.asarray(read_en), jnp.asarray(write_en)
 
     rk, wk, ren, wen = draw_tx()

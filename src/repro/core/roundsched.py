@@ -206,7 +206,12 @@ def fused_round(t: Transport, state, classes: Sequence[dict], *,
                 lambda st, r, m, fn=h.fn, rw=h.reply_words:
                     vector_apply(fn, st, r, m, rw)
             )(state, recs, msk)
-    # 2) serial (mutating) handlers fold through node state in class order
+    # 2) serial (mutating) handlers fold through node state in class order.
+    # The nodes' folds are independent and run one node after another
+    # (lax.map): vmapped, the fold's per-node slot reads/writes and its
+    # fixed-offset word writes asked for opposite layouts of the (N, words)
+    # cluster arena, and the TPU compiler relaid the whole arena out twice
+    # per inbox cell
     for i, s in enumerate(specs):
         c = s["cls"]
         if c["kind"] == "rpc" and c["handler"].serial and s["cap"] > 0:
@@ -214,10 +219,9 @@ def fused_round(t: Transport, state, classes: Sequence[dict], *,
             s0, s1 = seg[i]
             recs = inbox[:, :, s0:s1, :s["W"]]
             msk = inbox_mask[:, :, s0:s1]
-            state, replies[i] = jax.vmap(
-                lambda st, r, m, fn=h.fn, rw=h.reply_words:
-                    serial_apply(fn, st, r, m, rw)
-            )(state, recs, msk)
+            state, replies[i] = lax.map(
+                lambda a, fn=h.fn, rw=h.reply_words: serial_apply(fn, *a, rw),
+                (state, recs, msk))
     # 3) one-sided gathers run last, on the post-handler state
     arena = None
     for i, s in enumerate(specs):

@@ -14,12 +14,13 @@ The single exchange primitive is dest-major -> source-major:
 
 which is precisely an all-to-all.  Two implementations:
 
-  * SimTransport  — an N-node cluster simulated on one device: cluster arrays
-    carry a leading node axis; exchange is a transpose.  Used by the
-    benchmarks (this container exposes a single CPU device) and by tests.
-  * MeshTransport — the production path: runs inside ``shard_map`` over a mesh
-    axis; exchange is ``lax.all_to_all``.  The dry-run proves it lowers and
-    compiles on the 512-chip mesh.
+  * SimTransport  — an N-node cluster simulated on one device (one TPU chip,
+    or the CPU in tests): cluster arrays carry a leading node axis; exchange
+    is a transpose in device memory.
+  * MeshTransport — one node per device: runs inside ``jax.shard_map`` over
+    a mesh axis; exchange is ``lax.all_to_all`` (ICI on a TPU host).
+    ``chip_smoke.py --chips 4`` runs it on a four-chip v5e host against
+    SimTransport(4).
 
 Protocol code is written once at cluster level: node-state arrays have one
 leading node axis (N, ...); in mesh mode that axis is the per-device shard

@@ -65,6 +65,16 @@ def _perm_lanes(x, perm):
     return jnp.take_along_axis(x, idx, axis=1)
 
 
+def _backoff_perm(t: Transport, key, B: int):
+    """Per-node backoff permutation of the B lane slots, (n_local, B) int32.
+    Node i draws from key i of the global split, so a shard of the cluster
+    (MeshTransport, one node per device) draws what the simulator's node i
+    draws — not a copy of node 0's permutation."""
+    keys = jax.random.split(key, t.n_nodes)[t.node_ids()]
+    return jax.vmap(lambda k: jax.random.permutation(k, B))(keys).astype(
+        jnp.int32)
+
+
 def tx_loop(t: Transport, state, cfg: ht.HashTableConfig, layout, *,
             read_keys, write_keys, write_values, read_enabled=None,
             write_enabled=None, cache=None, use_onesided: bool = True,
@@ -127,8 +137,7 @@ def tx_loop(t: Transport, state, cfg: ht.HashTableConfig, layout, *,
             rec.set_round(rnd)
             n0 = rec.buf.n
         key, sub = jax.random.split(key)
-        perm = jax.vmap(lambda k: jax.random.permutation(k, B))(
-            jax.random.split(sub, N)).astype(jnp.int32)
+        perm = _backoff_perm(t, sub, B)
         perm = jnp.where(rnd == 0, ident, perm)     # round 0 == single shot
         inv = jnp.argsort(perm, axis=1)
         active = ~done
@@ -338,8 +347,7 @@ def scan_loop(t: Transport, state, cfg, layout, *, scan_lo, scan_hi,
             rec.set_round(rnd)
             n0 = rec.buf.n
         key, sub = jax.random.split(key)
-        perm = jax.vmap(lambda k: jax.random.permutation(k, B))(
-            jax.random.split(sub, N)).astype(jnp.int32)
+        perm = _backoff_perm(t, sub, B)
         perm = jnp.where(rnd == 0, ident, perm)     # round 0 == single shot
         inv = jnp.argsort(perm, axis=1)
         active = ~done
