@@ -12,7 +12,6 @@ pipeline).  One round = ONE network round trip for every lane in flight.
 """
 from __future__ import annotations
 
-from functools import partial
 from typing import Optional
 
 import jax
@@ -20,10 +19,10 @@ import jax.numpy as jnp
 
 from repro.core import regions as rg
 from repro.core import roundsched as rs
+from repro.core import telemetry as T
 from repro.core.transport import Transport, route_by_dest, wire_for
 
 
-@partial(jax.named_call, name="storm_remote_read")
 def remote_read(t: Transport, arenas, dest, offsets, *, length: int,
                 capacity: Optional[int] = None,
                 mode: rg.AddressMode | None = None, page_tables=None,
@@ -50,7 +49,6 @@ def remote_read(t: Transport, arenas, dest, offsets, *, length: int,
     return out, ovf, stats
 
 
-@partial(jax.named_call, name="storm_remote_write")
 def remote_write(t: Transport, arenas, dest, offsets, values, *,
                  capacity: Optional[int] = None,
                  mode: rg.AddressMode | None = None, page_tables=None,
@@ -58,24 +56,38 @@ def remote_write(t: Transport, arenas, dest, offsets, values, *,
     """Batched one-sided WRITE (no reply payload — transport-level ack only).
 
     values: (N_local, B, L) uint32; enabled: optional (N_local, B) bool.
-    Returns (new_arenas, overflow, WireStats).
+    Returns (new_arenas, overflow, WireStats).  Its own exchange runs under
+    ``storm.round.other``, its parts under fused_round's part scopes (the
+    owner scatter under ``storm.gather``).
     """
+    with jax.named_scope(rs.round_scope(T.PH_OTHER)):
+        return _remote_write(t, arenas, dest, offsets, values,
+                             capacity=capacity, mode=mode,
+                             page_tables=page_tables, enabled=enabled,
+                             nic=nic)
+
+
+def _remote_write(t, arenas, dest, offsets, values, *, capacity, mode,
+                  page_tables, enabled, nic):
     B = dest.shape[-1]
     L = values.shape[-1]
     # capacity=0 must mean "deliver nothing", never silently "unbounded"
     cap = B if capacity is None else int(capacity)
     if cap < 0:
         raise ValueError(f"per-destination capacity must be >= 0, got {cap}")
-    if enabled is None:
-        enabled = jnp.ones(dest.shape, bool)
-    payload = jnp.concatenate(
-        [offsets[..., None].astype(jnp.uint32), values.astype(jnp.uint32)], axis=-1)
-    # disabled lanes are parked at the routing layer: no cell, no capacity
-    buf, mask, pos, ovf = jax.vmap(
-        lambda d, p, e: route_by_dest(d, p, t.n_nodes, cap, e)
-    )(dest, payload, enabled)
-    inbox = t.exchange(buf)
-    inbox_mask = t.exchange(mask)
+    with jax.named_scope("storm.pack"):
+        if enabled is None:
+            enabled = jnp.ones(dest.shape, bool)
+        payload = jnp.concatenate(
+            [offsets[..., None].astype(jnp.uint32), values.astype(jnp.uint32)],
+            axis=-1)
+        # disabled lanes are parked at the routing layer: no cell, no capacity
+        buf, mask, pos, ovf = jax.vmap(
+            lambda d, p, e: route_by_dest(d, p, t.n_nodes, cap, e)
+        )(dest, payload, enabled)
+    with jax.named_scope("storm.exchange"):
+        inbox = t.exchange(buf)
+        inbox_mask = t.exchange(mask)
 
     def owner_scatter(a, recs, msk, pt):
         off = recs[..., 0]
@@ -83,10 +95,13 @@ def remote_write(t: Transport, arenas, dest, offsets, values, *,
         return rg.arena_write(a, off, val, mode=mode, page_table=pt,
                               enabled=msk)
 
-    if mode is not None and mode.kind == "paged":
-        arenas = jax.vmap(owner_scatter)(arenas, inbox, inbox_mask, page_tables)
-    else:
-        arenas = jax.vmap(lambda a, r, m: owner_scatter(a, r, m, None))(
-            arenas, inbox, inbox_mask)
-    stats = wire_for(mask, req_words=1 + L, reply_words=0, nic=nic)
+    with jax.named_scope("storm.gather"):
+        if mode is not None and mode.kind == "paged":
+            arenas = jax.vmap(owner_scatter)(arenas, inbox, inbox_mask,
+                                             page_tables)
+        else:
+            arenas = jax.vmap(lambda a, r, m: owner_scatter(a, r, m, None))(
+                arenas, inbox, inbox_mask)
+    with jax.named_scope("storm.unpack"):
+        stats = wire_for(mask, req_words=1 + L, reply_words=0, nic=nic)
     return arenas, ovf, stats

@@ -58,6 +58,7 @@ import jax.numpy as jnp
 from jax import lax
 
 from repro.core import regions as rg
+from repro.core.telemetry import PHASE_NAMES
 from repro.core.transport import (Transport, per_dest_wire, pick_replies,
                                   route_by_dest, wire_for_classes)
 # Transport-level "request never delivered" status stamped into reply word 0
@@ -147,45 +148,69 @@ def fused_round(t: Transport, state, classes: Sequence[dict], *,
     count, the WireStats snapshot, per-destination message/byte counts —
     into the recorder's TraceBuffer.  Recording only READS round values:
     ``telemetry=None`` (the default) is bit-identical.
+
+    Named scopes (compile-time metadata only): the whole round runs under
+    ``storm.round.<phase name>``, and each of its parts under
+    ``storm.pack``, ``storm.exchange``, ``storm.handler.vector``,
+    ``storm.handler.serial``, ``storm.gather`` or ``storm.unpack``, so that
+    a profiler trace gives each layer's device time by name.
     """
+    with jax.named_scope(round_scope(phase)):
+        return _fused_round(t, state, classes, arena_key=arena_key, nic=nic,
+                            telemetry=telemetry, phase=phase)
+
+
+def round_scope(phase: int) -> str:
+    """The named scope of an exchange round tagged ``phase``."""
+    return "storm.round." + PHASE_NAMES.get(phase, str(phase))
+
+
+def _fused_round(t, state, classes, *, arena_key, nic, telemetry, phase):
     n_dst = t.n_nodes
     specs = []
-    for c in classes:
-        dest = c["dest"]
-        B_k = dest.shape[-1]
-        cap = c.get("capacity")
-        cap = B_k if cap is None else int(cap)
-        if cap < 0:
-            raise ValueError(f"per-destination capacity must be >= 0, got {cap}")
-        payload = c["payload"]
-        R_k = c["length"] if c["kind"] == "read" else c["handler"].reply_words
-        en = c.get("enabled")
-        if en is not None:
-            buf, mask, pos, ovf = jax.vmap(
-                lambda d, p, e: route_by_dest(d, p, n_dst, cap, e)
-            )(dest, payload, en)
-        else:
-            buf, mask, pos, ovf = jax.vmap(
-                lambda d, p: route_by_dest(d, p, n_dst, cap))(dest, payload)
-        specs.append(dict(cls=c, cap=cap, W=payload.shape[-1], R=R_k,
-                          buf=buf, mask=mask, pos=pos, ovf=ovf))
+    with jax.named_scope("storm.pack"):
+        for c in classes:
+            dest = c["dest"]
+            B_k = dest.shape[-1]
+            cap = c.get("capacity")
+            cap = B_k if cap is None else int(cap)
+            if cap < 0:
+                raise ValueError(
+                    f"per-destination capacity must be >= 0, got {cap}")
+            payload = c["payload"]
+            R_k = (c["length"] if c["kind"] == "read"
+                   else c["handler"].reply_words)
+            en = c.get("enabled")
+            if en is not None:
+                buf, mask, pos, ovf = jax.vmap(
+                    lambda d, p, e: route_by_dest(d, p, n_dst, cap, e)
+                )(dest, payload, en)
+            else:
+                buf, mask, pos, ovf = jax.vmap(
+                    lambda d, p: route_by_dest(d, p, n_dst, cap))(dest, payload)
+            specs.append(dict(cls=c, cap=cap, W=payload.shape[-1], R=R_k,
+                              buf=buf, mask=mask, pos=pos, ovf=ovf))
 
     c_total = sum(s["cap"] for s in specs)
     if c_total == 0:
         # nothing can be delivered this round: no exchange, no wire traffic
-        stats = wire_for_classes([s["mask"] for s in specs],
-                                 [s["W"] for s in specs],
-                                 [s["R"] for s in specs], nic=nic)
-        results = [(_dropped_replies(s), s["ovf"]) for s in specs]
-        _record_round(telemetry, phase, specs, stats)
+        with jax.named_scope("storm.unpack"):
+            stats = wire_for_classes([s["mask"] for s in specs],
+                                     [s["W"] for s in specs],
+                                     [s["R"] for s in specs], nic=nic)
+            results = [(_dropped_replies(s), s["ovf"]) for s in specs]
+            _record_round(telemetry, phase, specs, stats)
         return state, results, stats
 
     w_max = max(s["W"] for s in specs)
     r_max = max(s["R"] for s in specs)
-    send = jnp.concatenate([_pad_words(s["buf"], w_max) for s in specs], axis=2)
-    mask_all = jnp.concatenate([s["mask"] for s in specs], axis=2)
-    inbox = t.exchange(send)            # (N_local, n_src, C_total, w_max)
-    inbox_mask = t.exchange(mask_all)
+    with jax.named_scope("storm.pack"):
+        send = jnp.concatenate([_pad_words(s["buf"], w_max) for s in specs],
+                               axis=2)
+        mask_all = jnp.concatenate([s["mask"] for s in specs], axis=2)
+    with jax.named_scope("storm.exchange"):
+        inbox = t.exchange(send)        # (N_local, n_src, C_total, w_max)
+        inbox_mask = t.exchange(mask_all)
 
     seg = []
     base = 0
@@ -200,12 +225,13 @@ def fused_round(t: Transport, state, classes: Sequence[dict], *,
         if c["kind"] == "rpc" and not c["handler"].serial and s["cap"] > 0:
             h = c["handler"]
             s0, s1 = seg[i]
-            recs = inbox[:, :, s0:s1, :s["W"]]
-            msk = inbox_mask[:, :, s0:s1]
-            _, replies[i] = jax.vmap(
-                lambda st, r, m, fn=h.fn, rw=h.reply_words:
-                    vector_apply(fn, st, r, m, rw)
-            )(state, recs, msk)
+            with jax.named_scope("storm.handler.vector"):
+                recs = inbox[:, :, s0:s1, :s["W"]]
+                msk = inbox_mask[:, :, s0:s1]
+                _, replies[i] = jax.vmap(
+                    lambda st, r, m, fn=h.fn, rw=h.reply_words:
+                        vector_apply(fn, st, r, m, rw)
+                )(state, recs, msk)
     # 2) serial (mutating) handlers fold through node state in class order.
     # The nodes' folds are independent and run one node after another
     # (lax.map): vmapped, the fold's per-node slot reads/writes and its
@@ -217,11 +243,13 @@ def fused_round(t: Transport, state, classes: Sequence[dict], *,
         if c["kind"] == "rpc" and c["handler"].serial and s["cap"] > 0:
             h = c["handler"]
             s0, s1 = seg[i]
-            recs = inbox[:, :, s0:s1, :s["W"]]
-            msk = inbox_mask[:, :, s0:s1]
-            state, replies[i] = lax.map(
-                lambda a, fn=h.fn, rw=h.reply_words: serial_apply(fn, *a, rw),
-                (state, recs, msk))
+            with jax.named_scope("storm.handler.serial"):
+                recs = inbox[:, :, s0:s1, :s["W"]]
+                msk = inbox_mask[:, :, s0:s1]
+                state, replies[i] = lax.map(
+                    lambda a, fn=h.fn, rw=h.reply_words:
+                        serial_apply(fn, *a, rw),
+                    (state, recs, msk))
     # 3) one-sided gathers run last, on the post-handler state
     arena = None
     for i, s in enumerate(specs):
@@ -230,39 +258,45 @@ def fused_round(t: Transport, state, classes: Sequence[dict], *,
             if arena is None:
                 arena = state[arena_key]
             s0, s1 = seg[i]
-            offs = inbox[:, :, s0:s1, 0]
             mode = c.get("mode")
             length = c["length"]
-            if mode is not None and mode.kind == "paged":
-                replies[i] = jax.vmap(
-                    lambda a, pt, off, m=mode, ln=length:
-                        rg.arena_read(a, off, ln, m, pt)
-                )(arena, c["page_tables"], offs)
-            else:
-                replies[i] = jax.vmap(
-                    lambda a, off, ln=length: rg.arena_read(a, off, ln)
-                )(arena, offs)
+            with jax.named_scope("storm.gather"):
+                offs = inbox[:, :, s0:s1, 0]
+                if mode is not None and mode.kind == "paged":
+                    replies[i] = jax.vmap(
+                        lambda a, pt, off, m=mode, ln=length:
+                            rg.arena_read(a, off, ln, m, pt)
+                    )(arena, c["page_tables"], offs)
+                else:
+                    replies[i] = jax.vmap(
+                        lambda a, off, ln=length: rg.arena_read(a, off, ln)
+                    )(arena, offs)
 
-    back = t.exchange(jnp.concatenate(
-        [_pad_words(replies[i].astype(jnp.uint32), r_max)
-         if replies[i] is not None
-         else jnp.zeros(inbox.shape[:2] + (0, r_max), jnp.uint32)
-         for i in range(len(specs))], axis=2))
+    with jax.named_scope("storm.pack"):
+        back = jnp.concatenate(
+            [_pad_words(replies[i].astype(jnp.uint32), r_max)
+             if replies[i] is not None
+             else jnp.zeros(inbox.shape[:2] + (0, r_max), jnp.uint32)
+             for i in range(len(specs))], axis=2)
+    with jax.named_scope("storm.exchange"):
+        back = t.exchange(back)
 
-    results = []
-    for i, s in enumerate(specs):
-        if s["cap"] == 0:
-            results.append((_dropped_replies(s), s["ovf"]))
-            continue
-        s0, s1 = seg[i]
-        out = jax.vmap(pick_replies)(
-            back[:, :, s0:s1, :s["R"]], s["cls"]["dest"], s["pos"], s["ovf"])
-        results.append((_finalize_reply(s, out), s["ovf"]))
+    with jax.named_scope("storm.unpack"):
+        results = []
+        for i, s in enumerate(specs):
+            if s["cap"] == 0:
+                results.append((_dropped_replies(s), s["ovf"]))
+                continue
+            s0, s1 = seg[i]
+            out = jax.vmap(pick_replies)(
+                back[:, :, s0:s1, :s["R"]], s["cls"]["dest"], s["pos"],
+                s["ovf"])
+            results.append((_finalize_reply(s, out), s["ovf"]))
 
-    stats = wire_for_classes([s["mask"] for s in specs],
-                             [s["W"] for s in specs],
-                             [s["R"] for s in specs], nic=nic)
-    _record_round(telemetry, phase, specs, stats)
+        stats = wire_for_classes([s["mask"] for s in specs],
+                                 [s["W"] for s in specs],
+                                 [s["R"] for s in specs], nic=nic)
+        _record_round(telemetry, phase, specs, stats)
     return state, results, stats
 
 

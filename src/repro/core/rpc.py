@@ -22,10 +22,7 @@ Handlers come in two flavours:
 from __future__ import annotations
 
 import dataclasses
-from functools import partial
 from typing import Callable, Optional
-
-import jax
 
 from repro.core import roundsched as rs
 from repro.core.roundsched import serial_apply, vector_apply  # noqa: F401  (re-export)
@@ -51,7 +48,6 @@ class Handler:
     serial: bool = True
 
 
-@partial(jax.named_call, name="storm_rpc")
 def rpc_call(t: Transport, state, dest, records, handler: Handler, *,
              capacity: Optional[int] = None, enabled=None, nic=None,
              telemetry=None, phase: int = 0):

@@ -38,10 +38,11 @@ bench gate pins the round-trip schedule).
 
 The threading idiom is a MUTABLE HOLDER, not a return value: a
 :class:`Recorder` passed down the call tree accumulates the traced
-:class:`TraceBuffer` value by assignment during tracing (jax's ``named_call``
-is a pure name-scope here, so no trace boundary is crossed), and the loop
-body that created it threads ``recorder.buf`` back into its scan carry.  That
-keeps every dataplane function's return signature unchanged.
+:class:`TraceBuffer` value by assignment during tracing (the dataplane's
+``jax.named_scope`` layers are names only, so no trace boundary is
+crossed), and the loop body that created it threads ``recorder.buf`` back
+into its scan carry.  That keeps every dataplane function's return
+signature unchanged.
 """
 from __future__ import annotations
 

@@ -168,6 +168,7 @@ def _validate_from_bytes(read_ctx, vbuf, vovf):
 # cluster state plus a plain dict of per-item arrays; lane axes are flattened
 # to (N, B*K) like the wire sees them.
 # ---------------------------------------------------------------------------
+@jax.named_scope("storm.occ.read")
 def execute_read_set(t: Transport, state, cfg: ht.HashTableConfig, layout, *,
                      read_keys, read_enabled, cache=None,
                      use_onesided: bool = True, capacity: Optional[int] = None,
@@ -191,6 +192,7 @@ def execute_read_set(t: Transport, state, cfg: ht.HashTableConfig, layout, *,
         versions=rvers, node=rnode, slot=rslot, overflow=rovf, metrics=m)
 
 
+@jax.named_scope("storm.occ.lock")
 def lock_write_set(t: Transport, state, cfg: ht.HashTableConfig, layout,
                    serial_h, *, write_keys, write_enabled,
                    capacity: Optional[int] = None, nic=None, ptable=None,
@@ -210,6 +212,7 @@ def lock_write_set(t: Transport, state, cfg: ht.HashTableConfig, layout,
     return state, lctx
 
 
+@jax.named_scope("storm.occ.validate")
 def validate_read_set(t: Transport, state, layout, read_ctx, *,
                       capacity: Optional[int] = None, nic=None,
                       offset_of=None, telemetry=None):
@@ -326,6 +329,7 @@ def commit_or_abort(t: Transport, state, serial_h, lock_ctx, *, commit_lane,
 # ---------------------------------------------------------------------------
 # Shared tail: commit decision, abort classification, result packing.
 # ---------------------------------------------------------------------------
+@jax.named_scope("storm.occ.commit")
 def _decide_and_finish(t, state, serial_h, *, N, B, Rd, Wr, write_enabled,
                        write_values, rctx, lctx, vctx, read_wire,
                        onesided_success, rpc_fallback, total,
@@ -400,15 +404,20 @@ def _run_transactions_fused(t: Transport, state, cfg, layout, *, read_keys,
     N, B, Rd = read_keys.shape[:3]
     Wr = write_keys.shape[2]
     serial_h = ht.make_rpc_handler(cfg, layout)
-    rk_lo = read_keys[..., 0].reshape(N, B * Rd)
-    rk_hi = read_keys[..., 1].reshape(N, B * Rd)
-    ren = read_enabled.reshape(N, B * Rd)
 
     # ---- round 1: one-sided read of the read set --------------------------
-    probe = hy.onesided_probe(t, state, rk_lo, rk_hi, cfg, layout, cache=cache,
-                              use_onesided=use_onesided, capacity=capacity,
-                              enabled=ren, nic=nic, ptable=ptable,
-                              telemetry=telemetry)
+    with jax.named_scope("storm.occ.read"):
+        rk_lo = read_keys[..., 0].reshape(N, B * Rd)
+        rk_hi = read_keys[..., 1].reshape(N, B * Rd)
+        ren = read_enabled.reshape(N, B * Rd)
+        probe = hy.onesided_probe(t, state, rk_lo, rk_hi, cfg, layout,
+                                  cache=cache, use_onesided=use_onesided,
+                                  capacity=capacity, enabled=ren, nic=nic,
+                                  ptable=ptable, telemetry=telemetry)
+        counts = dict(
+            onesided_success=jnp.sum(probe["success"].astype(jnp.float32)),
+            rpc_fallback=jnp.sum(probe["need_rpc"].astype(jnp.float32)),
+            total=jnp.sum(ren.astype(jnp.float32)))
 
     # ---- round 2: read-set RPC fallback ∥ LOCK ∥ validate(one-sided hits) -
     # The fallback is independent of LOCK (different key sets, the lookup is
@@ -418,47 +427,54 @@ def _run_transactions_fused(t: Transport, state, cfg, layout, *, read_keys,
     # ordering provides.  Under an explicit capacity bound the validate phase
     # keeps its own round instead, so its send-queue back-pressure policy
     # stays bit-identical to the reference's single validate round.
-    lk, lock_recs = _lock_requests(t, cfg, layout, write_keys=write_keys,
-                                   write_enabled=write_enabled, ptable=ptable)
-    lookup_recs = ht.make_record(W.OP_LOOKUP, rk_lo, rk_hi)
-    vector_h = ht.make_lookup_handler_vector(cfg, layout)
-    classes = [
-        rs.rpc_class(probe["node"], lookup_recs, vector_h,
-                     enabled=probe["need_rpc"], capacity=capacity),
-        rs.rpc_class(lk["node"], lock_recs, serial_h, enabled=lk["enabled"],
-                     capacity=capacity),
-    ]
     fuse_v1 = capacity is None and Rd > 0
-    if fuse_v1:
-        classes.append(rs.read_class(
-            probe["node"], ht.slot_idx_offset(layout, probe["slot_idx"]),
-            length=sl.SLOT_WORDS, enabled=ren & probe["success"]))
-    state, results, s2 = rs.fused_round(t, state, classes, nic=nic,
-                                        telemetry=telemetry, phase=T.PH_LOCK)
-    lookup_rep, lookup_ovf = results[0]
-    lrep, lovf = results[1]
+    with jax.named_scope("storm.occ.lock"):
+        lk, lock_recs = _lock_requests(t, cfg, layout, write_keys=write_keys,
+                                       write_enabled=write_enabled,
+                                       ptable=ptable)
+        lookup_recs = ht.make_record(W.OP_LOOKUP, rk_lo, rk_hi)
+        vector_h = ht.make_lookup_handler_vector(cfg, layout)
+        classes = [
+            rs.rpc_class(probe["node"], lookup_recs, vector_h,
+                         enabled=probe["need_rpc"], capacity=capacity),
+            rs.rpc_class(lk["node"], lock_recs, serial_h,
+                         enabled=lk["enabled"], capacity=capacity),
+        ]
+        if fuse_v1:
+            classes.append(rs.read_class(
+                probe["node"], ht.slot_idx_offset(layout, probe["slot_idx"]),
+                length=sl.SLOT_WORDS, enabled=ren & probe["success"]))
+        state, results, s2 = rs.fused_round(t, state, classes, nic=nic,
+                                            telemetry=telemetry,
+                                            phase=T.PH_LOCK)
+        lookup_rep, lookup_ovf = results[0]
+        lrep, lovf = results[1]
 
-    lctx = _parse_lock_replies(lk, lrep, lovf, N, B, Wr)
-    mg = hy.merge_rpc_fallback(probe, lookup_rep, lookup_ovf)
-    cache = hy.update_lookup_cache(cfg, cache, rk_lo, rk_hi, probe["node"],
-                                   mg["slot_idx"], mg["found"])
-    rctx = dict(key_lo=rk_lo, key_hi=rk_hi, enabled=ren, found=mg["found"],
-                values=mg["value"], versions=mg["version"],
-                node=probe["node"], slot=mg["slot_idx"],
-                overflow=mg["overflow"])
+        lctx = _parse_lock_replies(lk, lrep, lovf, N, B, Wr)
+        mg = hy.merge_rpc_fallback(probe, lookup_rep, lookup_ovf)
+        cache = hy.update_lookup_cache(cfg, cache, rk_lo, rk_hi,
+                                       probe["node"], mg["slot_idx"],
+                                       mg["found"])
+        rctx = dict(key_lo=rk_lo, key_hi=rk_hi, enabled=ren,
+                    found=mg["found"], values=mg["value"],
+                    versions=mg["version"], node=probe["node"],
+                    slot=mg["slot_idx"], overflow=mg["overflow"])
 
     # ---- round 3: validate re-reads whose address came from the RPC -------
     # (empty — and therefore free of wire cost — on the one-sided fast path)
     if fuse_v1:
-        v1buf = results[2][0]
-        v2buf, _, s3 = osd.remote_read(
-            t, state["arena"], probe["node"],
-            ht.slot_idx_offset(layout, mg["slot_idx"]), length=sl.SLOT_WORDS,
-            enabled=ren & mg["rpc_ok"], nic=nic, telemetry=telemetry,
-            phase=T.PH_VALIDATE)
-        vbuf = jnp.where(probe["success"][..., None], v1buf, v2buf)
-        # without a capacity bound neither validate sub-round can overflow
-        vctx = _validate_from_bytes(rctx, vbuf, jnp.zeros((N, B * Rd), bool))
+        with jax.named_scope("storm.occ.validate"):
+            v1buf = results[2][0]
+            v2buf, _, s3 = osd.remote_read(
+                t, state["arena"], probe["node"],
+                ht.slot_idx_offset(layout, mg["slot_idx"]),
+                length=sl.SLOT_WORDS, enabled=ren & mg["rpc_ok"], nic=nic,
+                telemetry=telemetry, phase=T.PH_VALIDATE)
+            vbuf = jnp.where(probe["success"][..., None], v1buf, v2buf)
+            # without a capacity bound neither validate sub-round can
+            # overflow
+            vctx = _validate_from_bytes(rctx, vbuf,
+                                        jnp.zeros((N, B * Rd), bool))
         vctx["wire"] = s3
     else:
         vctx = validate_read_set(t, state, layout, rctx, capacity=capacity,
@@ -471,10 +487,7 @@ def _run_transactions_fused(t: Transport, state, cfg, layout, *, read_keys,
     state, res = _decide_and_finish(
         t, state, serial_h, N=N, B=B, Rd=Rd, Wr=Wr,
         write_enabled=write_enabled, write_values=write_values,
-        rctx=rctx, lctx=lctx, vctx=vctx, read_wire=probe["wire"],
-        onesided_success=jnp.sum(probe["success"].astype(jnp.float32)),
-        rpc_fallback=jnp.sum(probe["need_rpc"].astype(jnp.float32)),
-        total=jnp.sum(ren.astype(jnp.float32)),
+        rctx=rctx, lctx=lctx, vctx=vctx, read_wire=probe["wire"], **counts,
         capacity=capacity, nic=nic, rep=rep, ptable=ptable,
         telemetry=telemetry)
     return state, cache, res
@@ -750,83 +763,92 @@ def run_scan_transactions(t: Transport, state, cfg: bt.BTreeConfig, layout, *,
     serial_h = bt.make_rpc_handler(cfg, layout)
     scan_h = bt.make_scan_handler_vector(cfg, layout)
 
-    # client-side plan from the cached inner nodes (meta has a leading
-    # client axis; each node plans its own lanes)
-    plan = jax.vmap(
-        lambda sep, nl, lo, hi: bt.scan_plan(cfg, sep, nl, lo, hi)
-    )(meta["sep"], meta["nleaf"], scan_lo, scan_hi)
-    en = plan["enabled"] & scan_enabled[..., None]              # (N, B, S)
-    en_f = en.reshape(N, B * S)
-    dest = plan["node"].reshape(N, B * S)
-    pleaf = plan["leaf"].reshape(N, B * S)
-    pfence = plan["fence"].reshape(N, B * S)
-
     # ---- round 1: one-sided reads of the planned leaves -------------------
-    buf, ovf1, s1 = osd.remote_read(
-        t, state["arena"], dest, bt.leaf_offset(cfg, layout, pleaf),
-        length=cfg.leaf_words, capacity=capacity, enabled=en_f, nic=nic,
-        telemetry=telemetry, phase=T.PH_READ)
-    p1 = bt.parse_leaf(cfg, buf)
-    # a position is resolved one-sided iff the image is stable and its
-    # immutable low fence matches the plan (stale separators can only MISS
-    # leaves, never mis-assign fences)
-    pos_ok = (en_f & ~ovf1 & (p1["version"] % 2 == 0) & (p1["lock"] == 0)
-              & (p1["fence_lo"] == pfence))
-    need = en_f & ~pos_ok
-    scan_recs = bt.make_record(W.OP_BT_SCAN, pfence, jnp.zeros_like(pfence))
-    lk, lock_recs = _bt_lock_requests(t, cfg, write_keys=write_keys,
-                                      write_enabled=write_enabled,
-                                      ptable=ptable)
+    with jax.named_scope("storm.occ.read"):
+        # client-side plan from the cached inner nodes (meta has a leading
+        # client axis; each node plans its own lanes)
+        plan = jax.vmap(
+            lambda sep, nl, lo, hi: bt.scan_plan(cfg, sep, nl, lo, hi)
+        )(meta["sep"], meta["nleaf"], scan_lo, scan_hi)
+        en = plan["enabled"] & scan_enabled[..., None]          # (N, B, S)
+        en_f = en.reshape(N, B * S)
+        dest = plan["node"].reshape(N, B * S)
+        pleaf = plan["leaf"].reshape(N, B * S)
+        pfence = plan["fence"].reshape(N, B * S)
+        buf, ovf1, s1 = osd.remote_read(
+            t, state["arena"], dest, bt.leaf_offset(cfg, layout, pleaf),
+            length=cfg.leaf_words, capacity=capacity, enabled=en_f, nic=nic,
+            telemetry=telemetry, phase=T.PH_READ)
+        p1 = bt.parse_leaf(cfg, buf)
+        # a position is resolved one-sided iff the image is stable and its
+        # immutable low fence matches the plan (stale separators can only
+        # MISS leaves, never mis-assign fences)
+        pos_ok = (en_f & ~ovf1 & (p1["version"] % 2 == 0) & (p1["lock"] == 0)
+                  & (p1["fence_lo"] == pfence))
+        need = en_f & ~pos_ok
 
     fuse_v1 = fused and capacity is None and S > 0
-    if fused:
-        # ---- round 2: scan fallback ∥ LOCK ∥ validate(one-sided-resolved) -
-        classes = [
-            rs.rpc_class(dest, scan_recs, scan_h, enabled=need,
-                         capacity=capacity),
-            rs.rpc_class(lk["node"], lock_recs, serial_h,
-                         enabled=lk["enabled"], capacity=capacity),
-        ]
-        if fuse_v1:
-            classes.append(rs.read_class(
-                dest, _bt_leaf_offset_of(layout, bt.header_slot(cfg, pleaf)),
-                length=sl.SLOT_WORDS, enabled=pos_ok))
-        state, results, s2 = rs.fused_round(t, state, classes, nic=nic,
-                                            telemetry=telemetry,
-                                            phase=T.PH_LOCK)
-        scan_rep, scan_ovf = results[0]
-        lrep, lovf = results[1]
-        s_fallback = None
-    else:
-        # ---- reference rounds 2 and 3: fallback, then LOCK ----------------
-        state, scan_rep, scan_ovf, s_fallback = R.rpc_call(
-            t, state, dest, scan_recs, scan_h, capacity=capacity,
-            enabled=need, nic=nic, telemetry=telemetry, phase=T.PH_FALLBACK)
-        state, lrep, lovf, s2 = R.rpc_call(
-            t, state, lk["node"], lock_recs, serial_h, capacity=capacity,
-            enabled=lk["enabled"], nic=nic, telemetry=telemetry,
-            phase=T.PH_LOCK)
-    lctx = _parse_lock_replies(lk, lrep, lovf, N, B, Wr)
+    with jax.named_scope("storm.occ.lock"):
+        scan_recs = bt.make_record(W.OP_BT_SCAN, pfence,
+                                   jnp.zeros_like(pfence))
+        lk, lock_recs = _bt_lock_requests(t, cfg, write_keys=write_keys,
+                                          write_enabled=write_enabled,
+                                          ptable=ptable)
+        if fused:
+            # ---- round 2: scan fallback ∥ LOCK ∥ validate(one-sided) -----
+            classes = [
+                rs.rpc_class(dest, scan_recs, scan_h, enabled=need,
+                             capacity=capacity),
+                rs.rpc_class(lk["node"], lock_recs, serial_h,
+                             enabled=lk["enabled"], capacity=capacity),
+            ]
+            if fuse_v1:
+                classes.append(rs.read_class(
+                    dest,
+                    _bt_leaf_offset_of(layout, bt.header_slot(cfg, pleaf)),
+                    length=sl.SLOT_WORDS, enabled=pos_ok))
+            state, results, s2 = rs.fused_round(t, state, classes, nic=nic,
+                                                telemetry=telemetry,
+                                                phase=T.PH_LOCK)
+            scan_rep, scan_ovf = results[0]
+            lrep, lovf = results[1]
+            s_fallback = None
+        else:
+            # ---- reference rounds 2 and 3: fallback, then LOCK ------------
+            state, scan_rep, scan_ovf, s_fallback = R.rpc_call(
+                t, state, dest, scan_recs, scan_h, capacity=capacity,
+                enabled=need, nic=nic, telemetry=telemetry,
+                phase=T.PH_FALLBACK)
+            state, lrep, lovf, s2 = R.rpc_call(
+                t, state, lk["node"], lock_recs, serial_h, capacity=capacity,
+                enabled=lk["enabled"], nic=nic, telemetry=telemetry,
+                phase=T.PH_LOCK)
+        lctx = _parse_lock_replies(lk, lrep, lovf, N, B, Wr)
 
-    # merge the authoritative fallback leaf images over the one-sided reads
-    rpc_ok = need & (scan_rep[..., 0] == W.ST_OK) & ~scan_ovf
-    mbuf = jnp.where(rpc_ok[..., None], scan_rep[..., 2:], buf)
-    mslot = jnp.where(rpc_ok, scan_rep[..., 1], bt.header_slot(cfg, pleaf))
-    p = bt.parse_leaf(cfg, mbuf)
-    resolved = pos_ok | rpc_ok
-    rctx = dict(key_lo=p["fence_lo"], key_hi=jnp.zeros_like(p["fence_lo"]),
-                enabled=en_f, found=resolved, versions=p["version"],
-                node=dest, slot=mslot, overflow=need & scan_ovf)
+        # merge the authoritative fallback leaf images over the one-sided
+        # reads
+        rpc_ok = need & (scan_rep[..., 0] == W.ST_OK) & ~scan_ovf
+        mbuf = jnp.where(rpc_ok[..., None], scan_rep[..., 2:], buf)
+        mslot = jnp.where(rpc_ok, scan_rep[..., 1],
+                          bt.header_slot(cfg, pleaf))
+        p = bt.parse_leaf(cfg, mbuf)
+        resolved = pos_ok | rpc_ok
+        rctx = dict(key_lo=p["fence_lo"],
+                    key_hi=jnp.zeros_like(p["fence_lo"]), enabled=en_f,
+                    found=resolved, versions=p["version"], node=dest,
+                    slot=mslot, overflow=need & scan_ovf)
 
     # ---- validate the leaf read set (headers) -----------------------------
     if fuse_v1:
-        v1 = results[2][0]
-        v2, _, s3 = osd.remote_read(
-            t, state["arena"], dest, _bt_leaf_offset_of(layout, mslot),
-            length=sl.SLOT_WORDS, enabled=rpc_ok, nic=nic,
-            telemetry=telemetry, phase=T.PH_VALIDATE)
-        vbuf = jnp.where(pos_ok[..., None], v1, v2)
-        vctx = _validate_from_bytes(rctx, vbuf, jnp.zeros((N, B * S), bool))
+        with jax.named_scope("storm.occ.validate"):
+            v1 = results[2][0]
+            v2, _, s3 = osd.remote_read(
+                t, state["arena"], dest, _bt_leaf_offset_of(layout, mslot),
+                length=sl.SLOT_WORDS, enabled=rpc_ok, nic=nic,
+                telemetry=telemetry, phase=T.PH_VALIDATE)
+            vbuf = jnp.where(pos_ok[..., None], v1, v2)
+            vctx = _validate_from_bytes(rctx, vbuf,
+                                        jnp.zeros((N, B * S), bool))
         vctx["wire"] = s3
     else:
         vctx = validate_read_set(t, state, layout, rctx, capacity=capacity,
@@ -836,55 +858,59 @@ def run_scan_transactions(t: Transport, state, cfg: bt.BTreeConfig, layout, *,
     lctx["wire"] = s2
 
     # ---- decide, commit / abort, classify ---------------------------------
-    complete, truncated = _scan_chain(
-        cfg, p["fence_lo"].reshape(N, B, S), p["fence_hi"].reshape(N, B, S),
-        scan_lo, scan_hi, en, resolved.reshape(N, B, S))
-    lane_locks_ok = jnp.all(
-        (lctx["lock_ok"] | ~lctx["enabled"]).reshape(N, B, Wr), axis=-1)
-    lane_valid = jnp.all(
-        (vctx["valid"] | ~en_f).reshape(N, B, S), axis=-1) & complete
-    lane_reads_ok = ~jnp.any(
-        (rctx["overflow"] | vctx["overflow"]).reshape(N, B, S), axis=-1)
+    with jax.named_scope("storm.occ.commit"):
+        complete, truncated = _scan_chain(
+            cfg, p["fence_lo"].reshape(N, B, S),
+            p["fence_hi"].reshape(N, B, S), scan_lo, scan_hi, en,
+            resolved.reshape(N, B, S))
+        lane_locks_ok = jnp.all(
+            (lctx["lock_ok"] | ~lctx["enabled"]).reshape(N, B, Wr), axis=-1)
+        lane_valid = jnp.all(
+            (vctx["valid"] | ~en_f).reshape(N, B, S), axis=-1) & complete
+        lane_reads_ok = ~jnp.any(
+            (rctx["overflow"] | vctx["overflow"]).reshape(N, B, S), axis=-1)
 
-    commit_lane = lane_locks_ok & lane_valid & lane_reads_ok
-    state, cctx = _bt_commit_or_abort(
-        t, state, serial_h, lctx, commit_lane=commit_lane,
-        write_values=write_values, capacity=capacity, nic=nic, rep=rep,
-        ptable=ptable, telemetry=telemetry)
+        commit_lane = lane_locks_ok & lane_valid & lane_reads_ok
+        state, cctx = _bt_commit_or_abort(
+            t, state, serial_h, lctx, commit_lane=commit_lane,
+            write_values=write_values, capacity=capacity, nic=nic, rep=rep,
+            ptable=ptable, telemetry=telemetry)
 
-    has_writes = jnp.any(write_enabled, axis=-1)
-    commit_delivered = ~jnp.any(cctx["overflow"].reshape(N, B, Wr), axis=-1)
-    committed = jnp.where(has_writes, commit_lane & commit_delivered,
-                          lane_valid & lane_reads_ok)
+        has_writes = jnp.any(write_enabled, axis=-1)
+        commit_delivered = ~jnp.any(cctx["overflow"].reshape(N, B, Wr),
+                                    axis=-1)
+        committed = jnp.where(has_writes, commit_lane & commit_delivered,
+                              lane_valid & lane_reads_ok)
 
-    lane_ovf = (~lane_reads_ok
-                | jnp.any(lctx["no_space"].reshape(N, B, Wr), axis=-1)
-                | jnp.any(cctx["overflow"].reshape(N, B, Wr), axis=-1))
-    lane_stale = jnp.any(lctx["stale"].reshape(N, B, Wr), axis=-1)
-    lane_lock_fail = jnp.any(lctx["lock_fail"].reshape(N, B, Wr), axis=-1)
-    aborted = ~committed
-    aborted_overflow = aborted & lane_ovf
-    aborted_stale = aborted & ~lane_ovf & lane_stale
-    aborted_lock = aborted & ~lane_ovf & ~lane_stale & lane_lock_fail
-    aborted_validate = (aborted & ~lane_ovf & ~lane_stale & ~lane_lock_fail
-                        & ~lane_valid)
+        lane_ovf = (~lane_reads_ok
+                    | jnp.any(lctx["no_space"].reshape(N, B, Wr), axis=-1)
+                    | jnp.any(cctx["overflow"].reshape(N, B, Wr), axis=-1))
+        lane_stale = jnp.any(lctx["stale"].reshape(N, B, Wr), axis=-1)
+        lane_lock_fail = jnp.any(lctx["lock_fail"].reshape(N, B, Wr),
+                                 axis=-1)
+        aborted = ~committed
+        aborted_overflow = aborted & lane_ovf
+        aborted_stale = aborted & ~lane_ovf & lane_stale
+        aborted_lock = aborted & ~lane_ovf & ~lane_stale & lane_lock_fail
+        aborted_validate = (aborted & ~lane_ovf & ~lane_stale
+                            & ~lane_lock_fail & ~lane_valid)
 
-    # ---- scan payload: records of validated leaves inside [lo, hi] --------
-    keys = p["keys"].reshape(N, B, S, cfg.leaf_width)
-    values = p["values"].reshape(N, B, S, cfg.leaf_width, sl.VALUE_WORDS)
-    live = p["live"].reshape(N, B, S, cfg.leaf_width)
-    in_range = (live & (keys >= scan_lo[..., None, None])
-                & (keys <= scan_hi[..., None, None])
-                & (resolved.reshape(N, B, S) & en)[..., None])
+        # ---- scan payload: records of validated leaves inside [lo, hi] ----
+        keys = p["keys"].reshape(N, B, S, cfg.leaf_width)
+        values = p["values"].reshape(N, B, S, cfg.leaf_width, sl.VALUE_WORDS)
+        live = p["live"].reshape(N, B, S, cfg.leaf_width)
+        in_range = (live & (keys >= scan_lo[..., None, None])
+                    & (keys <= scan_hi[..., None, None])
+                    & (resolved.reshape(N, B, S) & en)[..., None])
 
-    wire = read_wire + lctx["wire"] + vctx["wire"] + cctx["wire"]
-    metrics = hy.HybridMetrics(
-        onesided_success=jnp.sum(pos_ok.astype(jnp.float32)),
-        rpc_fallback=jnp.sum(need.astype(jnp.float32)),
-        total=jnp.sum(en_f.astype(jnp.float32)),
-        wire=wire)
-    rts = (read_wire.round_trips + lctx["wire"].round_trips
-           + vctx["wire"].round_trips + cctx["wire"].round_trips)
+        wire = read_wire + lctx["wire"] + vctx["wire"] + cctx["wire"]
+        metrics = hy.HybridMetrics(
+            onesided_success=jnp.sum(pos_ok.astype(jnp.float32)),
+            rpc_fallback=jnp.sum(need.astype(jnp.float32)),
+            total=jnp.sum(en_f.astype(jnp.float32)),
+            wire=wire)
+        rts = (read_wire.round_trips + lctx["wire"].round_trips
+               + vctx["wire"].round_trips + cctx["wire"].round_trips)
     return state, ScanTxResult(
         committed=committed,
         scan_keys=keys, scan_values=values, scan_mask=in_range,

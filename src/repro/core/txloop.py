@@ -75,6 +75,7 @@ def _backoff_perm(t: Transport, key, B: int):
         jnp.int32)
 
 
+@jax.named_scope("storm.txloop")
 def tx_loop(t: Transport, state, cfg: ht.HashTableConfig, layout, *,
             read_keys, write_keys, write_values, read_enabled=None,
             write_enabled=None, cache=None, use_onesided: bool = True,
@@ -115,6 +116,11 @@ def tx_loop(t: Transport, state, cfg: ht.HashTableConfig, layout, *,
 
     Returns (state, cache, TxLoopResult) — plus a ``telemetry.TelemetryOut``
     as a fourth element when ``telemetry`` is enabled.
+
+    The loop's own work (backoff, lane permutes, per-round counts, the scan
+    itself) runs under the named scope ``storm.txloop``; each protocol
+    round's work carries its ``storm.occ.*`` and ``storm.round.*`` scopes
+    inside it.
     """
     N, B, Rd = read_keys.shape[:3]
     if read_enabled is None:
@@ -276,6 +282,7 @@ class ScanLoopResult:
     round_trips: jnp.ndarray          # scalar
 
 
+@jax.named_scope("storm.txloop")
 def scan_loop(t: Transport, state, cfg, layout, *, scan_lo, scan_hi,
               meta=None, write_keys=None, write_values=None,
               scan_enabled=None, write_enabled=None,
@@ -300,7 +307,8 @@ def scan_loop(t: Transport, state, cfg, layout, *, scan_lo, scan_hi,
       telemetry:  optional telemetry.TelemetryConfig — same flight recorder
                   as tx_loop's (``None`` = bit-identical, round-identical).
     Returns (state, meta, ScanLoopResult) — plus a ``telemetry.TelemetryOut``
-    as a fourth element when ``telemetry`` is enabled."""
+    as a fourth element when ``telemetry`` is enabled.  Named scopes as in
+    tx_loop."""
     from repro.core.datastructs import btree as bt
 
     N, B = scan_lo.shape
