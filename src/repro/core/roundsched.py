@@ -51,6 +51,7 @@ class in its own round (tests/test_tx_fused_equivalence.py).
 """
 from __future__ import annotations
 
+import dataclasses
 from typing import Optional, Sequence
 
 import jax
@@ -65,6 +66,7 @@ from repro.core.transport import (Transport, per_dest_wire, pick_replies,
 # of overflowed/parked RPC lanes (registered with every other status in
 # core/wireproto.py; rpc.py re-exports it too).
 from repro.core.wireproto import ST_DROPPED  # noqa: F401  (re-export)
+from repro.core.wireproto import ST_BAD_OP
 
 
 # ---------------------------------------------------------------------------
@@ -72,22 +74,37 @@ from repro.core.wireproto import ST_DROPPED  # noqa: F401  (re-export)
 # cycle; rpc.py re-exports both names).
 # ---------------------------------------------------------------------------
 def serial_apply(handler_fn, state, records, mask, reply_words: int):
-    """Fold records through node state in a fixed serialization order.
+    """Fold the live records through node state in a fixed serialization
+    order.
 
     handler_fn(state, record (W,), valid) -> (state, reply (reply_words,))
-    records: (S, C, W); mask: (S, C) -> replies (S, C, reply_words)
+    records: (S, C, W); mask: (S, C) -> replies (S, C, reply_words) uint32
+
+    Only the live cells are folded, in flat (source-major) scan order: the
+    loop runs as many handler steps as ``mask`` holds, not S * C.  A masked
+    cell is a no-op by the handler contract, so skipping it leaves state as
+    the full scan left it; its reply is ``[ST_BAD_OP, 0, ...]``.
     """
     S, C, W = records.shape
-    flat_r = records.reshape(S * C, W)
-    flat_m = mask.reshape(S * C)
+    n = S * C
+    flat_r = records.reshape(n, W)
+    flat_m = mask.reshape(n)
+    # stable compaction: live indices ascend, so scan order (= lock order)
+    # is the full scan's
+    live_idx = jnp.nonzero(flat_m, size=n, fill_value=0)[0]
+    n_live = jnp.sum(flat_m.astype(jnp.int32))
+    replies = jnp.zeros((n, reply_words), jnp.uint32).at[:, :1].set(
+        jnp.uint32(ST_BAD_OP))
+    valid = jnp.asarray(True)
 
-    def step(st, rm):
-        rec, valid = rm
-        st, rep = handler_fn(st, rec, valid)
-        return st, rep
+    def step(i, carry):
+        st, out = carry
+        j = live_idx[i]
+        st, rep = handler_fn(st, flat_r[j], valid)
+        return st, out.at[j].set(rep.astype(jnp.uint32))
 
-    state, flat_rep = lax.scan(step, state, (flat_r, flat_m))
-    return state, flat_rep.reshape(S, C, reply_words)
+    state, replies = lax.fori_loop(0, n_live, step, (state, replies))
+    return state, replies.reshape(S, C, reply_words)
 
 
 def vector_apply(handler_fn, state, records, mask, reply_words: int):
@@ -232,12 +249,14 @@ def _fused_round(t, state, classes, *, arena_key, nic, telemetry, phase):
                     lambda st, r, m, fn=h.fn, rw=h.reply_words:
                         vector_apply(fn, st, r, m, rw)
                 )(state, recs, msk)
-    # 2) serial (mutating) handlers fold through node state in class order.
-    # The nodes' folds are independent and run one node after another
-    # (lax.map): vmapped, the fold's per-node slot reads/writes and its
-    # fixed-offset word writes asked for opposite layouts of the (N, words)
-    # cluster arena, and the TPU compiler relaid the whole arena out twice
-    # per inbox cell
+    # 2) serial (mutating) handlers fold through node state in class order,
+    # each node's fold stepping through its live inbox cells only, in scan
+    # order.  The nodes' folds are independent and run one node after
+    # another (lax.map): vmapped, the fold's per-node slot reads/writes and
+    # its fixed-offset word writes asked for opposite layouts of the
+    # (N, words) cluster arena, and the TPU compiler relaid the whole arena
+    # out twice per inbox cell
+    serial_steps = jnp.zeros((), jnp.float32)
     for i, s in enumerate(specs):
         c = s["cls"]
         if c["kind"] == "rpc" and c["handler"].serial and s["cap"] > 0:
@@ -250,6 +269,7 @@ def _fused_round(t, state, classes, *, arena_key, nic, telemetry, phase):
                     lambda a, fn=h.fn, rw=h.reply_words:
                         serial_apply(fn, *a, rw),
                     (state, recs, msk))
+                serial_steps = serial_steps + jnp.sum(msk.astype(jnp.float32))
     # 3) one-sided gathers run last, on the post-handler state
     arena = None
     for i, s in enumerate(specs):
@@ -296,6 +316,7 @@ def _fused_round(t, state, classes, *, arena_key, nic, telemetry, phase):
         stats = wire_for_classes([s["mask"] for s in specs],
                                  [s["W"] for s in specs],
                                  [s["R"] for s in specs], nic=nic)
+        stats = dataclasses.replace(stats, serial_steps=serial_steps)
         _record_round(telemetry, phase, specs, stats)
     return state, results, stats
 

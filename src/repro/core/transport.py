@@ -226,6 +226,10 @@ class WireStats:
         default_factory=lambda: jnp.zeros((), jnp.float32))
     nic_penalty_us: jnp.ndarray = dataclasses.field(  # sum(ops * penalty_us)
         default_factory=lambda: jnp.zeros((), jnp.float32))
+    # owner side: serial handler steps run (roundsched.serial_apply folds
+    # the live serial inbox cells only, so this counts them)
+    serial_steps: jnp.ndarray = dataclasses.field(
+        default_factory=lambda: jnp.zeros((), jnp.float32))
 
     @staticmethod
     def zero():
