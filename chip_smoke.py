@@ -263,13 +263,17 @@ def four_chips(args):
         for k in ml:
             check(np.array_equal(ml[k], sl_[k]),
                   f"{name}: per-lane {k} differs between mesh and simulator")
+        ici, sim_ici = float(mc.pop("ici_bytes")), float(sc.pop("ici_bytes"))
+        check(ici > 0 and sim_ici == 0,
+              f"{name}: ICI bytes {ici} on the mesh, {sim_ici} on one chip")
         for k in mc:
             check(np.array_equal(mc[k], sc[k]),
                   f"{name}: psum'd {k} counts differ from the simulator's")
         log(f"{name}: mesh {mdt * 1e3:.3f} ms, simulator {sdt * 1e3:.3f} ms, "
-            f"committed {int(ml['committed'].sum())}/{N * tatp.LANES}; "
-            "per-lane committed/commit_round/read_found/read_values and "
-            "per-round counts equal")
+            f"committed {int(ml['committed'].sum())}/{N * tatp.LANES}, "
+            f"round trips {float(mc['round_trips'])}, {ici:.0f} bytes over "
+            "ICI; per-lane committed/commit_round/read_found/read_values, "
+            "per-round counts and round trips equal")
         check_batch(mcl, lookup, ms, ref, b, ml, sc, rng, name)
     same_arenas(ms, ss, "after the batches")
 

@@ -87,7 +87,7 @@ def _remote_write(t, arenas, dest, offsets, values, *, capacity, mode,
         )(dest, payload, enabled)
     with jax.named_scope("storm.exchange"):
         inbox = t.exchange(buf)
-        inbox_mask = t.exchange(mask)
+        inbox_mask, sent, mask_bytes = t.exchange_mask(mask)
 
     def owner_scatter(a, recs, msk, pt):
         off = recs[..., 0]
@@ -103,5 +103,7 @@ def _remote_write(t, arenas, dest, offsets, values, *, capacity, mode,
             arenas = jax.vmap(lambda a, r, m: owner_scatter(a, r, m, None))(
                 arenas, inbox, inbox_mask)
     with jax.named_scope("storm.unpack"):
-        stats = wire_for(mask, req_words=1 + L, reply_words=0, nic=nic)
+        stats = rs.cluster_stats(
+            wire_for(mask, req_words=1 + L, reply_words=0, nic=nic), sent,
+            t.ici_bytes(buf) + mask_bytes)
     return arenas, ovf, stats

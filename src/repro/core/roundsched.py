@@ -142,7 +142,7 @@ def _pad_words(x, width):
 
 def fused_round(t: Transport, state, classes: Sequence[dict], *,
                 arena_key: str = "arena", nic=None, telemetry=None,
-                phase: int = 0):
+                phase: int = 0, want_replies: bool = True):
     """Run one fused exchange round carrying several traffic classes.
 
     state: pytree with leading node axis; read classes gather from
@@ -158,7 +158,10 @@ def fused_round(t: Transport, state, classes: Sequence[dict], *,
     transport configuration into the stats (None = perfect NIC).
     Overflowed/parked rpc lanes carry ST_DROPPED in reply word 0
     (never aliasing ST_OK or a handler-returned status); overflowed/parked
-    read lanes read back zeros.
+    read lanes read back zeros.  With ``want_replies=False`` the caller
+    reads no reply: the owners' replies never travel back (no return
+    exchange) and every ``reply`` is None; the modeled wire stats still
+    price the acks.
 
     ``telemetry`` (an optional :class:`repro.core.telemetry.Recorder`)
     appends ONE flight-recorder event for this round — phase tag, class
@@ -174,7 +177,8 @@ def fused_round(t: Transport, state, classes: Sequence[dict], *,
     """
     with jax.named_scope(round_scope(phase)):
         return _fused_round(t, state, classes, arena_key=arena_key, nic=nic,
-                            telemetry=telemetry, phase=phase)
+                            telemetry=telemetry, phase=phase,
+                            want_replies=want_replies)
 
 
 def round_scope(phase: int) -> str:
@@ -182,7 +186,8 @@ def round_scope(phase: int) -> str:
     return "storm.round." + PHASE_NAMES.get(phase, str(phase))
 
 
-def _fused_round(t, state, classes, *, arena_key, nic, telemetry, phase):
+def _fused_round(t, state, classes, *, arena_key, nic, telemetry, phase,
+                 want_replies):
     n_dst = t.n_nodes
     specs = []
     with jax.named_scope("storm.pack"):
@@ -227,7 +232,7 @@ def _fused_round(t, state, classes, *, arena_key, nic, telemetry, phase):
         mask_all = jnp.concatenate([s["mask"] for s in specs], axis=2)
     with jax.named_scope("storm.exchange"):
         inbox = t.exchange(send)        # (N_local, n_src, C_total, w_max)
-        inbox_mask = t.exchange(mask_all)
+        inbox_mask, sent, mask_bytes = t.exchange_mask(mask_all)
 
     seg = []
     base = 0
@@ -292,18 +297,24 @@ def _fused_round(t, state, classes, *, arena_key, nic, telemetry, phase):
                         lambda a, off, ln=length: rg.arena_read(a, off, ln)
                     )(arena, offs)
 
-    with jax.named_scope("storm.pack"):
-        back = jnp.concatenate(
-            [_pad_words(replies[i].astype(jnp.uint32), r_max)
-             if replies[i] is not None
-             else jnp.zeros(inbox.shape[:2] + (0, r_max), jnp.uint32)
-             for i in range(len(specs))], axis=2)
-    with jax.named_scope("storm.exchange"):
-        back = t.exchange(back)
+    back_bytes = 0
+    if want_replies:
+        with jax.named_scope("storm.pack"):
+            back = jnp.concatenate(
+                [_pad_words(replies[i].astype(jnp.uint32), r_max)
+                 if replies[i] is not None
+                 else jnp.zeros(inbox.shape[:2] + (0, r_max), jnp.uint32)
+                 for i in range(len(specs))], axis=2)
+        with jax.named_scope("storm.exchange"):
+            back_bytes = t.ici_bytes(back)
+            back = t.exchange(back)
 
     with jax.named_scope("storm.unpack"):
         results = []
         for i, s in enumerate(specs):
+            if not want_replies:
+                results.append((None, s["ovf"]))
+                continue
             if s["cap"] == 0:
                 results.append((_dropped_replies(s), s["ovf"]))
                 continue
@@ -316,9 +327,23 @@ def _fused_round(t, state, classes, *, arena_key, nic, telemetry, phase):
         stats = wire_for_classes([s["mask"] for s in specs],
                                  [s["W"] for s in specs],
                                  [s["R"] for s in specs], nic=nic)
+        stats = cluster_stats(stats, sent,
+                              t.ici_bytes(send) + mask_bytes + back_bytes)
         stats = dataclasses.replace(stats, serial_steps=serial_steps)
         _record_round(telemetry, phase, specs, stats)
     return state, results, stats
+
+
+def cluster_stats(stats, sent, ici_bytes):
+    """A round's stats with the bytes its exchanges put on the inter-chip
+    links, and, where the transport says whether any node of the cluster
+    sent (``Transport.exchange_mask``), the cluster's round trip in place
+    of this shard's."""
+    f32 = jnp.float32
+    stats = dataclasses.replace(stats, ici_bytes=jnp.asarray(ici_bytes, f32))
+    if sent is None:
+        return stats
+    return dataclasses.replace(stats, round_trips=sent.astype(f32))
 
 
 def _record_round(telemetry, phase, specs, stats):

@@ -43,6 +43,19 @@ class Transport:
     def exchange(self, x):
         raise NotImplementedError
 
+    def exchange_mask(self, mask):
+        """Exchange a round's live-cell mask (N_local, N_dst, C).  Returns
+        (exchange(mask), sent, ici_bytes): ``sent`` is whether any node of
+        the cluster sent a live cell this round, or None where this shard's
+        mask is the whole cluster's; ``ici_bytes`` is what the exchange put
+        on the inter-chip links (:meth:`ici_bytes`)."""
+        raise NotImplementedError
+
+    def ici_bytes(self, x) -> int:
+        """Bytes ``exchange(x)`` sends from this device to other devices,
+        from the operand's shape alone (padding words included)."""
+        raise NotImplementedError
+
     def node_ids(self):
         """Global ids of the nodes in this shard: (n_local,) int32."""
         raise NotImplementedError
@@ -61,6 +74,12 @@ class SimTransport(Transport):
         # x: (N_this, N_dst, C, ...) -> (N_this, N_src, C, ...)
         assert x.shape[0] == self.n_nodes and x.shape[1] == self.n_nodes, x.shape
         return jnp.swapaxes(x, 0, 1)
+
+    def exchange_mask(self, mask):
+        return self.exchange(mask), None, 0
+
+    def ici_bytes(self, x) -> int:
+        return 0
 
     def node_ids(self):
         return jnp.arange(self.n_nodes, dtype=jnp.int32)
@@ -84,6 +103,20 @@ class MeshTransport(Transport):
         # swap restores the (n_local=1, N_src, C, ...) source-major layout.
         y = lax.all_to_all(x, self.axis_name, split_axis=1, concat_axis=0, tiled=True)
         return jnp.swapaxes(y, 0, 1)
+
+    def exchange_mask(self, mask):
+        # one more cell per destination: whether this node sends anything
+        # this round.  Every node then learns whether any node did (the
+        # cluster's round trip) from the all_to_all the mask rides anyway,
+        # with no collective of its own
+        sent = jnp.broadcast_to(jnp.any(mask), mask.shape[:2] + (1,))
+        ext = jnp.concatenate([mask, sent], axis=2)
+        got = self.exchange(ext)
+        return got[..., :-1], jnp.any(got[..., -1]), self.ici_bytes(ext)
+
+    def ici_bytes(self, x) -> int:
+        # the chunks for the N - 1 other nodes leave the chip; its own stays
+        return x.size // self.n_nodes * (self.n_nodes - 1) * x.dtype.itemsize
 
     def node_ids(self):
         i = lax.axis_index(self.axis_name)
@@ -229,6 +262,11 @@ class WireStats:
     # owner side: serial handler steps run (roundsched.serial_apply folds
     # the live serial inbox cells only, so this counts them)
     serial_steps: jnp.ndarray = dataclasses.field(
+        default_factory=lambda: jnp.zeros((), jnp.float32))
+    # bytes this device's exchanges sent to other devices: Transport.
+    # ici_bytes of each operand a round exchanged, live cells or not (the
+    # collective runs whole); 0 under SimTransport
+    ici_bytes: jnp.ndarray = dataclasses.field(
         default_factory=lambda: jnp.zeros((), jnp.float32))
 
     @staticmethod

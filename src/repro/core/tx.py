@@ -316,9 +316,12 @@ def commit_or_abort(t: Transport, state, serial_h, lock_ctx, *, commit_lane,
             classes.append(rs.rpc_class(
                 _backup_dest(lock_ctx, rep, i, ptable), bk_recs, serial_h,
                 enabled=bk_en, capacity=capacity))
+    # the primary class's replies are never read (its lanes hold their
+    # locks); only backup writes report back
     state, results, s_cm = rs.fused_round(t, state, classes, nic=nic,
                                           telemetry=telemetry,
-                                          phase=T.PH_COMMIT)
+                                          phase=T.PH_COMMIT,
+                                          want_replies=bk_en is not None)
     overflow = results[0][1] & lock_ctx["lock_ok"]
     for brep, bovf in results[1:]:
         overflow = overflow | ((bovf | (brep[..., 0] == W.ST_NO_SPACE))
@@ -690,9 +693,12 @@ def _bt_commit_or_abort(t: Transport, state, serial_h, lock_ctx, *,
             classes.append(rs.rpc_class(
                 _backup_dest(lock_ctx, rep, i, ptable), bk_recs, serial_h,
                 enabled=bk_en, capacity=capacity))
+    # the primary class's replies are never read (its lanes hold their
+    # locks); only backup writes report back
     state, results, s_cm = rs.fused_round(t, state, classes, nic=nic,
                                           telemetry=telemetry,
-                                          phase=T.PH_COMMIT)
+                                          phase=T.PH_COMMIT,
+                                          want_replies=bk_en is not None)
     overflow = results[0][1] & lock_ctx["lock_ok"]
     for brep, bovf in results[1:]:
         bst = brep[..., 0]

@@ -22,9 +22,9 @@ deletes — one transaction per lane of a ``txloop.tx_loop`` batch with
 The same step code runs under ``SimTransport`` (``mesh=None``: every node's
 arrays carry a leading node axis on one device) and under ``MeshTransport``
 inside ``jax.shard_map`` (one node per device).  Under the mesh the
-per-round counts are psum'd over the node axis so they equal the
-simulator's cluster totals; round trips are counted per shard there, so
-only the simulator returns them.
+per-round counts are psum'd over the node axis (scope ``storm.allreduce``)
+so they equal the simulator's cluster totals; the round trips each shard
+counts are the cluster's already (``Transport.exchange_mask``).
 """
 from __future__ import annotations
 
@@ -179,16 +179,20 @@ class Cluster:
             return found, value
         return self.jit(step, (NODE,) * 4, (NODE, NODE))
 
-    def tx_step(self):
+    def tx_step(self, max_rounds: int = MAX_ROUNDS):
         """(state, read_keys, write_keys, write_values, read_enabled,
-        write_enabled, prng_key) -> (state, lanes, counts)."""
+        write_enabled, prng_key) -> (state, lanes, counts).
+
+        ``counts`` are the cluster's: the per-round transaction counts, the
+        bytes the batch's exchanges put on the inter-chip links
+        (``ici_bytes``, 0 on one device) and ``round_trips``."""
         cfg, layout, t = self.cfg, self.layout, self.t
 
         def step(state, rk, wk, wv, ren, wen, key):
             state, _, res = txl.tx_loop(
                 t, state, cfg, layout, read_keys=rk, write_keys=wk,
                 write_values=wv, read_enabled=ren, write_enabled=wen,
-                max_rounds=MAX_ROUNDS, key=key)
+                max_rounds=max_rounds, key=key)
             lanes = dict(committed=res.committed,
                          commit_round=res.commit_round,
                          read_found=res.read_found,
@@ -197,11 +201,13 @@ class Cluster:
                           attempts=res.round_attempts,
                           abort_lock=res.round_abort_lock,
                           abort_validate=res.round_abort_validate,
-                          abort_overflow=res.round_abort_overflow)
+                          abort_overflow=res.round_abort_overflow,
+                          ici_bytes=res.metrics.wire.ici_bytes)
             if self.mesh is not None:
-                counts = jax.tree.map(lambda x: lax.psum(x, "node"), counts)
-            else:
-                counts["round_trips"] = res.round_trips
+                with jax.named_scope("storm.allreduce"):
+                    counts = jax.tree.map(lambda x: lax.psum(x, "node"),
+                                          counts)
+            counts["round_trips"] = res.round_trips
             return state, lanes, counts
         return self.jit(step, (NODE,) * 6 + (REP,), (NODE, NODE, REP),
                         donate_argnums=0)

@@ -116,7 +116,6 @@ TX_SCRIPT = textwrap.dedent("""
         for args, key in batches:
             state, lanes, counts = tx(state, *cl.put(args),
                                       cl.put_replicated(key))
-            counts.pop("round_trips", None)     # per shard on the mesh
             out.append(jax.tree.map(np.asarray, (lanes, counts)))
         runs.append((out, np.asarray(state["arena"])))
 
@@ -125,6 +124,9 @@ TX_SCRIPT = textwrap.dedent("""
     for (ml, mc), (sl_, sc) in zip(mesh_out, sim_out):
         for k in ml:
             np.testing.assert_array_equal(ml[k], sl_[k], err_msg=k)
+        # the bytes put on inter-chip links: none on one device
+        assert mc.pop("ici_bytes") > 0 and sc.pop("ici_bytes") == 0
+        assert set(mc) == set(sc) and "round_trips" in mc
         for k in mc:
             np.testing.assert_array_equal(mc[k], sc[k], err_msg=k)
         retried += int(sc["attempts"][1:].sum())
@@ -149,6 +151,6 @@ def test_mesh_transport_matches_simulator():
 
 def test_mesh_tx_loop_matches_simulator():
     """Per-lane committed / commit_round / read values, the psum'd per-round
-    counts and the final arenas of a retried TATP batch are equal under
-    MeshTransport(4) and SimTransport(4)."""
+    counts, the cluster's round trips and the final arenas of a retried TATP
+    batch are equal under MeshTransport(4) and SimTransport(4)."""
     _run(TX_SCRIPT, "MESH_TX_OK")

@@ -6,9 +6,16 @@ reads them): ``storm.round.<phase>`` around each exchange round, the six
 parts of a round inside it, ``storm.occ.<step>`` around the client's OCC
 work and ``storm.txloop`` around the retry engine's.  Compiled on the CPU
 for the TATP shape (8 ``SimTransport`` nodes, 2 reads and 1 write a lane),
-unreplicated and with two backups, at a small table size.
+unreplicated and with two backups, at a small table size; and the four-node
+mesh step, whose collectives sit under ``storm.exchange`` (the all-to-alls)
+and ``storm.allreduce`` (the cross-chip psum of the counts).
 """
+import os
+import pathlib
 import re
+import subprocess
+import sys
+import textwrap
 
 import jax
 import jax.numpy as jnp
@@ -131,3 +138,53 @@ def test_every_layer_of_the_step_is_named(f):
     ops = operations(text)
     unscoped = [n for n, p in ops.items() if not SCOPE.search(p)]
     assert len(unscoped) < 0.05 * len(ops), (len(ops), unscoped)
+
+
+# The four-node mesh step (one MeshTransport node per device) compiled in a
+# subprocess that forces four host devices, beside the same step under
+# SimTransport(4); each writes its compiled program's text.
+MESH_SCRIPT = textwrap.dedent("""
+    import pathlib, sys
+    import jax
+    from jax.sharding import NamedSharding, PartitionSpec as P
+    from repro import tatp
+    from repro.core.datastructs import hashtable as ht
+
+    cfg = ht.HashTableConfig(n_nodes=4, n_buckets=256, bucket_width=1,
+                             n_overflow=128, max_chain=4)
+    mesh = jax.make_mesh((4,), ("node",))
+    for name, cl in (("mesh", tatp.Cluster(4, mesh=mesh, cfg=cfg)),
+                     ("sim", tatp.Cluster(4, cfg=cfg))):
+        *node, key = cl.tx_arg_shapes()
+        if cl.mesh is not None:
+            put = lambda s, spec: jax.ShapeDtypeStruct(
+                s.shape, s.dtype, sharding=NamedSharding(mesh, spec))
+            node = jax.tree.map(lambda s: put(s, P("node")), tuple(node))
+            key = put(key, P())
+        text = cl.tx_step().lower(*node, key).compile().as_text()
+        (pathlib.Path(sys.argv[1]) / f"{name}.hlo").write_text(text)
+""")
+COLLECTIVE = re.compile(r'^\s*(?:ROOT )?%\S+ = .*? (all-to-all|all-reduce)\('
+                        r'.*?op_name="([^"]*)"', re.MULTILINE)
+
+
+def test_the_mesh_step_names_its_collectives(tmp_path):
+    """The compiled mesh step places every all-to-all under
+    ``storm.exchange`` and the cross-chip psum of the counts under
+    ``storm.allreduce``, and adds no unscoped operation to the step."""
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               XLA_FLAGS="--xla_force_host_platform_device_count=4",
+               PYTHONPATH=str(pathlib.Path(__file__).resolve().parents[1]
+                              / "src"))
+    res = subprocess.run([sys.executable, "-c", MESH_SCRIPT, str(tmp_path)],
+                         capture_output=True, text=True, timeout=600, env=env)
+    assert res.returncode == 0, res.stderr[-3000:]
+    mesh, sim = ((tmp_path / f"{n}.hlo").read_text() for n in ("mesh", "sim"))
+    found = {}
+    for op, path in COLLECTIVE.findall(mesh):
+        found.setdefault(op, set()).add(SCOPE.findall(path)[-1])
+    assert found == {"all-to-all": {"exchange"}, "all-reduce": {"allreduce"}}
+    assert not COLLECTIVE.findall(sim)
+    unscoped = [len([n for n, p in operations(t).items()
+                     if not SCOPE.search(p)]) for t in (mesh, sim)]
+    assert unscoped[0] <= unscoped[1], unscoped
