@@ -56,6 +56,12 @@ class Transport:
         from the operand's shape alone (padding words included)."""
         raise NotImplementedError
 
+    def any_live(self, mask):
+        """Whether any node of the cluster has a True cell in ``mask`` (this
+        shard's lanes): the same scalar on every shard, so all enter a
+        gated round together or none does."""
+        raise NotImplementedError
+
     def node_ids(self):
         """Global ids of the nodes in this shard: (n_local,) int32."""
         raise NotImplementedError
@@ -80,6 +86,9 @@ class SimTransport(Transport):
 
     def ici_bytes(self, x) -> int:
         return 0
+
+    def any_live(self, mask):
+        return jnp.any(mask)
 
     def node_ids(self):
         return jnp.arange(self.n_nodes, dtype=jnp.int32)
@@ -117,6 +126,11 @@ class MeshTransport(Transport):
     def ici_bytes(self, x) -> int:
         # the chunks for the N - 1 other nodes leave the chip; its own stays
         return x.size // self.n_nodes * (self.n_nodes - 1) * x.dtype.itemsize
+
+    def any_live(self, mask):
+        with jax.named_scope("storm.allreduce"):
+            return lax.psum(jnp.sum(mask.astype(jnp.int32)),
+                            self.axis_name) > 0
 
     def node_ids(self):
         i = lax.axis_index(self.axis_name)

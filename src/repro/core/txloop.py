@@ -7,6 +7,9 @@ contention the batch converges instead of silently dropping work.  ``tx_loop``
 drives that retry:
 
   * a ``lax.scan`` over ``max_rounds`` protocol rounds, all shapes static;
+    a round after the first runs only while some lane of the cluster is
+    still live (``Transport.any_live``), so a batch that commits in round 0
+    pays for one round, not ``max_rounds``;
   * per-round lane re-enable masks: lanes that committed are parked (their
     reads/writes are disabled, so they cost no handler work, no send-queue
     capacity and no wire bytes — see transport.route_by_dest's enabled mask);
@@ -57,6 +60,7 @@ class TxLoopResult:
     round_abort_stale: jnp.ndarray    # stale placement routes, per round
     metrics: hy.HybridMetrics         # totals across all rounds
     round_trips: jnp.ndarray          # scalar
+    rounds_run: jnp.ndarray           # scalar int32 — round bodies executed
 
 
 def _perm_lanes(x, perm):
@@ -87,8 +91,12 @@ def tx_loop(t: Transport, state, cfg: ht.HashTableConfig, layout, *,
     Arguments mirror tx.run_transactions; additionally:
       max_rounds: static retry bound (>= 1).  Round 0 is identical to the
                   single-shot protocol; each later round re-runs only the
-                  still-aborted lanes with permuted send-queue slots.
-      key:        optional jax PRNG key for the backoff permutation.
+                  still-aborted lanes with permuted send-queue slots, and
+                  is skipped whole once no lane of the cluster is live
+                  (its counts and WireStats read 0; ``rounds_run`` counts
+                  the rounds that ran).
+      key:        optional jax PRNG key for the backoff permutation (split
+                  once per round, skipped or not).
       fused:      run each protocol round on the fused 3-4-exchange schedule
                   (default) or the per-phase 5-round reference.
       nic:        optional repro.core.nic.ConnTable (connection mode +
@@ -117,10 +125,11 @@ def tx_loop(t: Transport, state, cfg: ht.HashTableConfig, layout, *,
     Returns (state, cache, TxLoopResult) — plus a ``telemetry.TelemetryOut``
     as a fourth element when ``telemetry`` is enabled.
 
-    The loop's own work (backoff, lane permutes, per-round counts, the scan
-    itself) runs under the named scope ``storm.txloop``; each protocol
-    round's work carries its ``storm.occ.*`` and ``storm.round.*`` scopes
-    inside it.
+    The loop's own work (backoff, lane permutes, per-round counts, the
+    round gate, the scan itself) runs under the named scope
+    ``storm.txloop`` (the gate's cross-chip sum under ``storm.allreduce``);
+    each protocol round's work carries its ``storm.occ.*`` and
+    ``storm.round.*`` scopes inside it.
     """
     N, B, Rd = read_keys.shape[:3]
     if read_enabled is None:
@@ -135,14 +144,13 @@ def tx_loop(t: Transport, state, cfg: ht.HashTableConfig, layout, *,
     use_tel = telemetry is not None
     ident = jnp.broadcast_to(jnp.arange(B, dtype=jnp.int32)[None], (N, B))
 
-    def body(carry, rnd):
+    def protocol_round(carry, sub, rnd):
         state, cache, ptab, stale_in, done, commit_round, rfound, rvals, \
-            key, tb, lat = carry
+            tb, lat = carry
         rec = T.Recorder(telemetry, tb) if use_tel else None
         if use_tel:
             rec.set_round(rnd)
             n0 = rec.buf.n
-        key, sub = jax.random.split(key)
         perm = _backoff_perm(t, sub, B)
         perm = jnp.where(rnd == 0, ident, perm)     # round 0 == single shot
         inv = jnp.argsort(perm, axis=1)
@@ -208,7 +216,21 @@ def tx_loop(t: Transport, state, cfg: ht.HashTableConfig, layout, *,
                         abort_stale=stats["abort_stale"])
             tb = rec.buf
         return (state, cache, ptab, stale_out, done, commit_round, rfound,
-                rvals, key, tb, lat), stats
+                rvals, tb, lat), stats
+
+    def body(carry, rnd):
+        rc, key = carry                     # rc: protocol_round's carry
+        key, sub = jax.random.split(key)    # every round draws, run or not
+        done = rc[4]
+        # a round runs only while some lane of the cluster is live (round 0
+        # always: no lane is done yet); a skipped round leaves the carry as
+        # an empty one would and counts nothing — no work, no wire bytes
+        run = (rnd == 0) | t.any_live(~done)
+        stats = jax.eval_shape(protocol_round, rc, sub, rnd)[1]
+        skip = lambda rc, sub, rnd: (rc, jax.tree.map(
+            lambda s: jnp.zeros(s.shape, s.dtype), stats))
+        rc, stats = lax.cond(run, protocol_round, skip, rc, sub, rnd)
+        return (rc, key), (stats, run)
 
     init = (
         state, cache,
@@ -218,13 +240,12 @@ def tx_loop(t: Transport, state, cfg: ht.HashTableConfig, layout, *,
         jnp.full((N, B), -1, jnp.int32),
         jnp.zeros(read_enabled.shape, bool),
         jnp.zeros(read_enabled.shape + (sl.VALUE_WORDS,), jnp.uint32),
-        key,
         (T.make_buffer(t.n_nodes, T.loop_capacity(telemetry, max_rounds))
          if use_tel else jnp.zeros(())),
         jnp.zeros((N, B), jnp.float32) if use_tel else jnp.zeros(()),
     )
-    (state, cache, _, _, done, commit_round, rfound, rvals, _, tb,
-     lat), ys = lax.scan(body, init, jnp.arange(max_rounds))
+    ((state, cache, _, _, done, commit_round, rfound, rvals, tb, lat), _), \
+        (ys, ran) = lax.scan(body, (init, key), jnp.arange(max_rounds))
 
     metrics = jax.tree.map(lambda x: jnp.sum(x, axis=0), ys["metrics"])
     result = TxLoopResult(
@@ -241,6 +262,7 @@ def tx_loop(t: Transport, state, cfg: ht.HashTableConfig, layout, *,
         round_abort_stale=ys["abort_stale"],
         metrics=metrics,
         round_trips=jnp.sum(ys["round_trips"]),
+        rounds_run=jnp.sum(ran.astype(jnp.int32)),
     )
     if use_tel:
         return state, cache, result, T.TelemetryOut(trace=tb,

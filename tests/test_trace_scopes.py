@@ -4,11 +4,12 @@ The dataplane names its layers with ``jax.named_scope`` so that a profiler
 trace gives device time per layer by name (``chipbench/harness/layers.py``
 reads them): ``storm.round.<phase>`` around each exchange round, the six
 parts of a round inside it, ``storm.occ.<step>`` around the client's OCC
-work and ``storm.txloop`` around the retry engine's.  Compiled on the CPU
-for the TATP shape (8 ``SimTransport`` nodes, 2 reads and 1 write a lane),
-unreplicated and with two backups, at a small table size; and the four-node
-mesh step, whose collectives sit under ``storm.exchange`` (the all-to-alls)
-and ``storm.allreduce`` (the cross-chip psum of the counts).
+work and ``storm.txloop`` around the retry engine's, its round gate
+included.  Compiled on the CPU for the TATP shape (8 ``SimTransport`` nodes,
+2 reads and 1 write a lane), unreplicated and with two backups, at a small
+table size; and the four-node mesh step, whose collectives sit under
+``storm.exchange`` (the all-to-alls) and ``storm.allreduce`` (the
+cross-chip psums of the counts and of the round gate's live lanes).
 """
 import os
 import pathlib
@@ -91,7 +92,7 @@ def operations(text):
     return ops
 
 
-def compiled_step(f):
+def compiled_step(f, max_rounds=4):
     cfg = ht.HashTableConfig(n_nodes=N, n_buckets=256, bucket_width=1,
                              n_overflow=128, max_chain=4)
     layout = ht.build_layout(cfg)
@@ -102,7 +103,7 @@ def compiled_step(f):
         state, _, res = txl.tx_loop(
             t, state, cfg, layout, read_keys=rk, write_keys=wk,
             write_values=wv, read_enabled=ren, write_enabled=wen,
-            max_rounds=4, key=key, rep=rep)
+            max_rounds=max_rounds, key=key, rep=rep)
         return state, res.committed, res.round_trips
 
     S, u32 = jax.ShapeDtypeStruct, jnp.uint32
@@ -138,6 +139,24 @@ def test_every_layer_of_the_step_is_named(f):
     ops = operations(text)
     unscoped = [n for n, p in ops.items() if not SCOPE.search(p)]
     assert len(unscoped) < 0.05 * len(ops), (len(ops), unscoped)
+
+
+COND = re.compile(r'^\s*(?:ROOT )?%\S+ = .*? conditional\(.*?op_name="([^"]*)"',
+                  re.MULTILINE)
+
+
+@pytest.mark.parametrize("f", [0, 2])
+def test_the_round_gate_is_named(f):
+    """The conditional that skips a round with no live lane sits under
+    ``storm.txloop``, and the gate adds no unscoped operation: the step at
+    four rounds has no more than the ungated one-round step."""
+    gated, one = compiled_step(f), compiled_step(f, max_rounds=1)
+    gates = COND.findall(gated)
+    assert gates and all("txloop" in SCOPE.findall(p) for p in gates), gates
+    assert not COND.findall(one)
+    unscoped = [len([n for n, p in operations(t).items()
+                     if not SCOPE.search(p)]) for t in (gated, one)]
+    assert unscoped[0] <= unscoped[1], unscoped
 
 
 # The four-node mesh step (one MeshTransport node per device) compiled in a
